@@ -15,6 +15,7 @@ from benchmarks import (fig12_phases, fig13_memory, fig14_throughput,
                         fig19_state_transfer, fig20_spikes, roofline_table,
                         table1_startup)
 from benchmarks.common import fmt_csv
+from repro.launch.compile_cache import enable_compile_cache
 
 MODULES = [
     ("table1", table1_startup),
@@ -34,6 +35,7 @@ def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default=None, help="comma-separated module names")
     args = ap.parse_args()
+    enable_compile_cache()
     only = set(args.only.split(",")) if args.only else None
     print("name,us_per_call,derived")
     failed = []

@@ -6,7 +6,6 @@ text on the child — verifying it matches the parent exactly.
 
   PYTHONPATH=src python examples/quickstart.py
 """
-import dataclasses
 import time
 
 import jax
@@ -22,7 +21,7 @@ from repro.serving.engine import ServingEngine
 
 
 def main():
-    cfg = dataclasses.replace(get_arch("micro-small"), compute_dtype="float32")
+    cfg = get_arch("micro-small")
     net = Network()
     parent_node = NodeRuntime("parent", net)
     child_node = NodeRuntime("child", net)
@@ -48,7 +47,7 @@ def main():
     prompt = [11, 42, 7, 300]
     out = {}
     for tag, p in (("parent", params), ("child", child_params)):
-        eng = ServingEngine(cfg, p, backend="ref")
+        eng = ServingEngine(cfg, p, backend="auto")
         rid = eng.submit(prompt, max_tokens=8)
         out[tag] = eng.run_to_completion()[rid]
         print(f"{tag} generated: {out[tag]}")

@@ -25,6 +25,7 @@ from repro.net import Network
 from repro.fork import ForkPolicy
 from repro.distributed import ctx
 from repro.distributed.sharding import make_axis_env, params_shardings
+from repro.launch.mesh import make_dp_mesh
 from repro.models import lm
 from repro.models.flops import param_counts
 from repro.platform.node import NodeRuntime
@@ -32,11 +33,6 @@ from repro.training import checkpoint as ckpt
 from repro.training.data import TokenStream
 from repro.training.optimizer import init_opt_state
 from repro.training.train_step import TrainConfig, make_train_step
-
-
-def make_mesh(dp: int):
-    devs = np.asarray(jax.devices()[:dp]).reshape(dp, 1)
-    return jax.sharding.Mesh(devs, ("data", "model"))
 
 
 def shard_tree(tree, cfg, env):
@@ -68,7 +64,7 @@ def main():
     losses = []
 
     # ---- phase 1: dp=2, crash at 1/3 of the run, restart from checkpoint
-    mesh2 = make_mesh(2)
+    mesh2 = make_dp_mesh(2)
     env2 = make_axis_env(mesh2)
     params = lm.init_params(jax.random.PRNGKey(0), cfg)
     opt = init_opt_state(params)
@@ -120,7 +116,7 @@ def main():
           f"({child.stats['pages_rdma']} pages, descriptor "
           f"{len(donor.seeds[handle.handler_id].blob)} B — no checkpoint read)")
 
-    mesh4 = make_mesh(4)
+    mesh4 = make_dp_mesh(4)
     env4 = make_axis_env(mesh4)
     with ctx.use_env(env4):
         step_fn4 = jax.jit(make_train_step(cfg, tcfg), donate_argnums=(0, 1))

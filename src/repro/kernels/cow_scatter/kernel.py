@@ -17,7 +17,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-LANE = 128
+from repro.kernels.page_gather.kernel import LANE, tiled
 
 
 def _scatter_kernel(pt_ref, pages_ref, frames_ref, out_ref):
@@ -35,13 +35,12 @@ def _scatter_runs_kernel(starts_ref, lens_ref, offs_ref, pages_ref,
 
 @functools.partial(jax.jit, static_argnames=("interpret",), donate_argnums=(0,))
 def cow_scatter(frames, page_ids, pages, *, interpret: bool = True):
-    """frames: (F, E) pool; page_ids: (n,) int32 unique; pages: (n, E)."""
-    F, E = frames.shape
-    assert E % LANE == 0, f"page_elems must be lane-aligned, got {E}"
-    R = E // LANE
+    """frames: (F, E) pool, or tiled (F, E // 128, 128) — returned in the
+    layout given; page_ids: (n,) int32 unique; pages: (n, E)."""
+    dst = tiled(frames)
+    F, R, _ = dst.shape
     n = page_ids.shape[0]
-    src = pages.reshape(n, R, LANE).astype(frames.dtype)
-    dst = frames.reshape(F, R, LANE)
+    src = tiled(pages).astype(frames.dtype)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
@@ -59,7 +58,7 @@ def cow_scatter(frames, page_ids, pages, *, interpret: bool = True):
         input_output_aliases={2: 0},      # alias frames input -> output
         interpret=interpret,
     )(page_ids.astype(jnp.int32), src, dst)
-    return out.reshape(F, E)
+    return out.reshape(frames.shape)
 
 
 @functools.partial(jax.jit, static_argnames=("max_len", "interpret"),
@@ -70,21 +69,19 @@ def cow_scatter_runs(frames, starts, lens, offs, pages, *, max_len: int,
     their allocated frame extents as one fused scatter per run table — the
     inverse of :func:`page_gather_runs`.
 
-    frames: (F, E) pool; starts/lens/offs: (num_runs,) int32 describing
-    contiguous destination extents (``lens >= 1``, runs must not overlap —
+    frames: (F, E) pool or tiled, returned in the layout given;
+    starts/lens/offs: (num_runs,) int32 describing contiguous destination
+    extents (``lens >= 1``, runs must not overlap —
     each dirty page gets a fresh frame from the allocator); pages:
     (sum(lens), E) payload, run-major.  Grid step (i, j) writes payload row
     ``offs[i] + j`` into frame ``starts[i] + j``; steps past a run's end
     clamp to the run's last block (just written) and skip the store, so the
     aliased pool content outside the runs is untouched.
     """
-    F, E = frames.shape
-    assert E % LANE == 0, f"page_elems must be lane-aligned, got {E}"
-    R = E // LANE
+    dst = tiled(frames)
+    F, R, _ = dst.shape
     num_runs = starts.shape[0]
-    n = pages.shape[0]
-    src = pages.reshape(n, R, LANE).astype(frames.dtype)
-    dst = frames.reshape(F, R, LANE)
+    src = tiled(pages).astype(frames.dtype)
 
     def _clamp(i, j, lens):
         return jnp.minimum(j, lens[i] - 1)
@@ -112,4 +109,4 @@ def cow_scatter_runs(frames, starts, lens, offs, pages, *, max_len: int,
         interpret=interpret,
     )(starts.astype(jnp.int32), lens.astype(jnp.int32),
       offs.astype(jnp.int32), src, dst)
-    return out.reshape(F, E)
+    return out.reshape(frames.shape)

@@ -16,9 +16,7 @@ from repro.kernels.cow_scatter.ref import cow_scatter_ref
 from repro.kernels.page_gather.ref import expand_runs
 
 
-@jax.jit
-def _set_jit(frames, ids, pages):
-    return frames.at[ids].set(pages.astype(frames.dtype))
+_set_jit = jax.jit(cow_scatter_ref)
 
 
 @functools.partial(jax.jit, static_argnames=("npages", "page_elems"))
@@ -36,8 +34,9 @@ def _patch_jit(t, ids, rows, *, npages, page_elems):
 
 
 def cow_scatter(frames, page_ids, pages, *, backend: str = "auto"):
-    """Commit COW pages into pool frames: frames (F, E); page_ids (n,)
-    unique int32; pages (n, E) -> updated frames."""
+    """Commit COW pages into pool frames: frames (F, E) or the device
+    pool's tiled (F, E // 128, 128); page_ids (n,) unique int32; pages
+    (n, E) -> updated frames, in the layout given."""
     page_ids = jnp.asarray(page_ids, jnp.int32)
     if page_ids.shape[0] == 0:
         return frames

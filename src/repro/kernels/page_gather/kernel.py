@@ -8,6 +8,10 @@ RDMA (`pltpu.make_async_remote_copy`); the on-chip structure is identical.
 
 Pages are viewed as (rows, 128) tiles: 128-lane alignment is mandatory on
 TPU, and page_elems is a multiple of 128 by construction (memory/pool.py).
+On TPU, reshaping an (F, page_elems) array into those tiles is a relayout
+copy of the whole array, so device pools hold their frames tiled already
+(`tiled` passes a 3-D array through untouched) and a launch costs what it
+moves, not the size of the pool.
 """
 from __future__ import annotations
 
@@ -19,6 +23,16 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 LANE = 128
+
+
+def tiled(x):
+    """(n, E) pages -> the kernels' (n, E // LANE, LANE) tile layout; an
+    already-tiled 3-D array is returned as is."""
+    if x.ndim == 3:
+        return x
+    n, E = x.shape
+    assert E % LANE == 0, f"page_elems must be lane-aligned, got {E}"
+    return x.reshape(n, E // LANE, LANE)
 
 
 def _copy_kernel(pt_ref, src_ref, out_ref):
@@ -35,12 +49,11 @@ def _copy_runs_kernel(starts_ref, lens_ref, offs_ref, src_ref, out_ref):
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def page_gather(frames, page_ids, *, interpret: bool = True):
-    """frames: (F, page_elems); page_ids: (n,) int32 -> (n, page_elems)."""
-    F, E = frames.shape
-    assert E % LANE == 0, f"page_elems must be lane-aligned, got {E}"
-    R = E // LANE
+    """frames: (F, page_elems) or tiled (F, page_elems // 128, 128);
+    page_ids: (n,) int32 -> (n, page_elems)."""
+    src = tiled(frames)
+    R = src.shape[1]
     n = page_ids.shape[0]
-    src = frames.reshape(F, R, LANE)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
@@ -56,7 +69,7 @@ def page_gather(frames, page_ids, *, interpret: bool = True):
         out_shape=jax.ShapeDtypeStruct((n, R, LANE), frames.dtype),
         interpret=interpret,
     )(page_ids.astype(jnp.int32), src)
-    return out.reshape(n, E)
+    return out.reshape(n, R * LANE)
 
 
 @functools.partial(jax.jit, static_argnames=("max_len", "n_out", "interpret"))
@@ -66,7 +79,7 @@ def page_gather_runs(frames, starts, lens, offs, *, max_len: int, n_out: int,
     maximal contiguous runs — exactly the SGE list PR 3's fault handler
     posts — instead of one id per page.
 
-    frames: (F, page_elems); starts/lens/offs: (num_runs,) int32 with
+    frames: (F, page_elems) or tiled; starts/lens/offs: (num_runs,) int32 with
     ``lens >= 1`` (empty runs are filtered at the ops layer) and
     ``offs = exclusive cumsum(lens)``; ``n_out = sum(lens)`` pages out.
 
@@ -77,11 +90,9 @@ def page_gather_runs(frames, starts, lens, offs, *, max_len: int, n_out: int,
     run's end clamp their index map to the run's last block (already
     written at step ``lens[i]-1``) and skip the store.
     """
-    F, E = frames.shape
-    assert E % LANE == 0, f"page_elems must be lane-aligned, got {E}"
-    R = E // LANE
+    src = tiled(frames)
+    R = src.shape[1]
     num_runs = starts.shape[0]
-    src = frames.reshape(F, R, LANE)
 
     def _clamp(i, j, lens):
         return jnp.minimum(j, lens[i] - 1)
@@ -105,4 +116,4 @@ def page_gather_runs(frames, starts, lens, offs, *, max_len: int, n_out: int,
         interpret=interpret,
     )(starts.astype(jnp.int32), lens.astype(jnp.int32),
       offs.astype(jnp.int32), src)
-    return out.reshape(n_out, E)
+    return out.reshape(n_out, R * LANE)

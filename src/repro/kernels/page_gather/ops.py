@@ -20,7 +20,14 @@ from repro.kernels.page_gather.ref import (expand_runs, page_gather_ref,
 
 @jax.jit
 def _take_jit(frames, ids):
-    return jnp.take(frames, ids, axis=0)
+    return jnp.take(frames, ids, axis=0).reshape(ids.shape[0], -1)
+
+
+def _page_elems(frames) -> int:
+    if frames.ndim not in (2, 3):
+        raise ValueError("frames must be (F, page_elems) or tiled "
+                         f"(F, page_elems // 128, 128), got {frames.shape}")
+    return int(np.prod(frames.shape[1:]))
 
 
 @functools.partial(jax.jit, static_argnames=("size", "shape", "out_dtype"))
@@ -32,14 +39,14 @@ def _assemble_jit(frames, ids, *, size, shape, out_dtype):
 
 
 def page_gather(frames, page_ids, *, backend: str = "auto"):
-    """Gather pool frames by page id: frames (F, E); page_ids (n,) int32
-    -> (n, E).  ``backend`` is resolved by ``kernels.dispatch`` (auto |
-    kernel | interpret | jnp | ref)."""
+    """Gather pool frames by page id: frames (F, E) or the device pool's
+    tiled (F, E // 128, 128); page_ids (n,) int32 -> (n, E).  ``backend``
+    is resolved by ``kernels.dispatch`` (auto | kernel | interpret | jnp |
+    ref)."""
     page_ids = jnp.asarray(page_ids, jnp.int32)
-    if frames.ndim != 2:
-        raise ValueError(f"frames must be (F, page_elems), got {frames.shape}")
+    E = _page_elems(frames)
     if page_ids.shape[0] == 0:
-        return jnp.zeros((0, frames.shape[1]), frames.dtype)
+        return jnp.zeros((0, E), frames.dtype)
     impl, interpret = dispatch.resolve_backend(backend,
                                                kernel_name="page_gather")
     if impl == dispatch.IMPL_REF:
@@ -54,14 +61,13 @@ def page_gather_runs(frames, starts, lens, *, backend: str = "auto"):
     is one contiguous frame extent (one SGE).  Returns (sum(lens), E),
     run-major.  Zero-length runs are filtered here; the kernels require
     ``lens >= 1``."""
-    if frames.ndim != 2:
-        raise ValueError(f"frames must be (F, page_elems), got {frames.shape}")
+    E = _page_elems(frames)
     starts_np = np.atleast_1d(np.asarray(starts, np.int64)).ravel()
     lens_np = np.atleast_1d(np.asarray(lens, np.int64)).ravel()
     keep = lens_np > 0
     starts_np, lens_np = starts_np[keep], lens_np[keep]
     if starts_np.size == 0:
-        return jnp.zeros((0, frames.shape[1]), frames.dtype)
+        return jnp.zeros((0, E), frames.dtype)
     impl, interpret = dispatch.resolve_backend(backend,
                                                kernel_name="page_gather")
     if impl == dispatch.IMPL_REF:

@@ -6,8 +6,9 @@ import numpy as np
 
 
 def page_gather_ref(frames, page_ids):
-    """frames: (F, page_elems); page_ids: (n,) int32 -> (n, page_elems)."""
-    return jnp.take(frames, page_ids, axis=0)
+    """frames: (F, page_elems) or tiled (F, page_elems // 128, 128);
+    page_ids: (n,) int32 -> (n, page_elems)."""
+    return jnp.take(frames, page_ids, axis=0).reshape(len(page_ids), -1)
 
 
 def expand_runs(starts, lens) -> np.ndarray:
@@ -32,4 +33,4 @@ def expand_runs(starts, lens) -> np.ndarray:
 def page_gather_runs_ref(frames, starts, lens):
     """Run-table gather oracle: frames (F, E); starts/lens (num_runs,) with
     lens >= 0 -> (sum(lens), E), run-major."""
-    return jnp.take(frames, expand_runs(starts, lens), axis=0)
+    return page_gather_ref(frames, expand_runs(starts, lens))

@@ -7,25 +7,29 @@ import jax.numpy as jnp
 
 def paged_attention_ref(q, kv_pages_k, kv_pages_v, page_table, lengths,
                         starts=None, v_page_table=None):
-    """q: (B, K, G, hd); kv pages: (F, Tp, K, hd); page_table: (B, P) int32;
-    lengths: (B,) int32; starts: optional (B,) window lower bound.
-    Returns (B, K, G, hd).
+    """q: (B, K, G, hd); kv pages: (F, K, Tp, hd) head-major;
+    page_table: (B, P) int32; lengths: (B,) int32; starts: optional (B,)
+    window lower bound.  Returns (B, K, G, hd).
 
     Slot t of sequence b lives at page page_table[b, t // Tp], row t % Tp.
     """
     B, K, G, hd = q.shape
-    F, Tp, _, _ = kv_pages_k.shape
+    F, _, Tp, _ = kv_pages_k.shape
     P = page_table.shape[1]
     if starts is None:
         starts = jnp.zeros_like(lengths)
     if v_page_table is None:
         v_page_table = page_table
-    k = jnp.take(kv_pages_k, page_table, axis=0).reshape(B, P * Tp, K, hd)
-    v = jnp.take(kv_pages_v, v_page_table, axis=0).reshape(B, P * Tp, K, hd)
-    scores = jnp.einsum("bkgh,bskh->bkgs", q.astype(jnp.float32),
-                        k.astype(jnp.float32)) * (hd ** -0.5)
+
+    def seqs(pages, table):               # (B, P, K, Tp, hd) -> (B, K, S, hd)
+        x = jnp.take(pages, table, axis=0).transpose(0, 2, 1, 3, 4)
+        return x.reshape(B, K, P * Tp, hd).astype(jnp.float32)
+
+    k, v = seqs(kv_pages_k, page_table), seqs(kv_pages_v, v_page_table)
+    scores = jnp.einsum("bkgh,bksh->bkgs", q.astype(jnp.float32),
+                        k) * (hd ** -0.5)
     t = jnp.arange(P * Tp)[None, :]
     mask = (t < lengths[:, None]) & (t >= starts[:, None])      # (B, S)
     scores = jnp.where(mask[:, None, None, :], scores, -1e30)
     w = jax.nn.softmax(scores, axis=-1)
-    return jnp.einsum("bkgs,bskh->bkgh", w, v.astype(jnp.float32)).astype(q.dtype)
+    return jnp.einsum("bkgs,bksh->bkgh", w, v).astype(q.dtype)

@@ -7,7 +7,6 @@ Usage:
 from __future__ import annotations
 
 import argparse
-import dataclasses
 import time
 
 import jax
@@ -17,6 +16,7 @@ from repro.configs.base import get_arch
 from repro.core.instance import ModelInstance
 from repro.net import Network
 from repro.fork import ForkPolicy
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import lm
 from repro.platform.node import NodeRuntime
 from repro.serving.engine import ServingEngine
@@ -30,8 +30,9 @@ def main(argv=None):
     ap.add_argument("--max-tokens", type=int, default=8)
     ap.add_argument("--fork-demo", action="store_true")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
-    cfg = dataclasses.replace(get_arch(args.arch), compute_dtype="float32")
+    cfg = get_arch(args.arch)
     net = Network()
     nodes = [NodeRuntime(f"node{i}", net, cache_enabled=True)
              for i in range(args.nodes)]
@@ -53,7 +54,7 @@ def main(argv=None):
         dt = time.perf_counter() - t0
         print(f"[serve] {node.node_id}: forked replica in {dt*1e3:.1f} ms "
               f"({child.stats['pages_rdma']} pages via RDMA)")
-        engines.append(ServingEngine(cfg, child_params, backend="ref"))
+        engines.append(ServingEngine(cfg, child_params, backend="auto"))
 
     rng = jax.random.PRNGKey(1)
     for i in range(args.requests):

@@ -20,6 +20,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import get_arch, reduce_for_smoke
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import lm
 from repro.models.flops import param_counts
 from repro.training import checkpoint as ckpt
@@ -50,6 +51,7 @@ def main(argv=None):
     ap.add_argument("--log-every", type=int, default=10)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_arch(args.arch)
     if args.smoke:

@@ -13,7 +13,9 @@ Two flavors share one interface:
   decomposed into maximal contiguous extents and moved as slice copies
   (one memcpy per extent) instead of per-page fancy indexing, mirroring
   on the CPU exactly what the doorbell-batched wire path does with SGEs.
-* **device pool** (``device=True``) — frames are a device (jnp) array and
+* **device pool** (``device=True``) — frames are a device (jnp) array,
+  held in the kernels' (F, page_elems // 128, 128) tile layout so no
+  launch relayouts the whole pool, and
   the data plane routes through the Pallas kernels: ``write_pages`` is a
   ``cow_scatter`` commit, ``read_pages``/``assemble`` are ``page_gather``
   launches (compiled on TPU, fused-XLA elsewhere — kernels/dispatch.py).
@@ -35,6 +37,7 @@ import numpy as np
 
 from repro.kernels import dispatch
 from repro.kernels.cow_scatter.ops import cow_scatter, cow_scatter_runs
+from repro.kernels.page_gather.kernel import LANE
 from repro.kernels.page_gather.ops import (gather_assemble, page_gather,
                                            page_gather_runs)
 
@@ -85,9 +88,16 @@ class PagePool:
         # clusters reserve their working set and never pay a copy
         self.initial_frames = initial_frames
         self.device = device
+        if device and page_elems % LANE:
+            raise ValueError(f"device pools need page_elems divisible by "
+                             f"{LANE}, got {page_elems}")
+        # per-frame shape of the frames array: device frames are tiled
+        self._frame_shape = ((page_elems // LANE, LANE) if device
+                             else (page_elems,))
         self.kernel_backend = kernel_backend
         self.meter = meter
-        self._frames: Dict[str, object] = {}    # dtype name -> (F, page_elems)
+        # dtype name -> (F,) + _frame_shape
+        self._frames: Dict[str, object] = {}
         self._free: Dict[str, List[int]] = {}       # kept sorted ascending
         self._allocated: Dict[str, set] = {}
 
@@ -113,7 +123,8 @@ class PagePool:
     def _ensure_capacity(self, dt: str, n: int):
         if dt not in self._frames:
             zeros = jnp.zeros if self.device else np.zeros
-            self._frames[dt] = zeros((self.initial_frames, self.page_elems),
+            self._frames[dt] = zeros((self.initial_frames,)
+                                     + self._frame_shape,
                                      dtype=self._np_dtype(dt))
             self._free[dt] = list(range(self.initial_frames))
             self._allocated[dt] = set()
@@ -126,7 +137,8 @@ class PagePool:
                        old.shape[0])
             xp = jnp if self.device else np
             self._frames[dt] = xp.concatenate(
-                [old, xp.zeros((grow, self.page_elems), dtype=old.dtype)])
+                [old, xp.zeros((grow,) + self._frame_shape,
+                               dtype=old.dtype)])
             self._free[dt].extend(range(old.shape[0], old.shape[0] + grow))
 
     # -- alloc/free ----------------------------------------------------------
@@ -262,22 +274,23 @@ class PagePool:
         else:
             dst[idx] = pages
 
-    def write_rows(self, dtype, frames, slots, rows, row_elems: int) -> None:
-        """In-place row update within pages: frames (B,), slots (B,),
-        rows (B, row_elems). Used by the serving engine's token appends."""
+    def write_rows(self, dtype, frames, slots, rows) -> None:
+        """In-place row update within head-major pages: frames (B,), slots
+        (B,), rows (B, H, W).  Each page is viewed as (H, T, W) and row
+        ``slots[i]`` of all H segments of ``frames[i]`` takes ``rows[i]`` —
+        one token's K (or V) across kv heads, the serving engine's append."""
         dt = self._dt(dtype)
         fidx = np.asarray(frames, np.int32)
         sidx = np.asarray(slots, np.int32)
+        B, H, W = rows.shape
+        arr = self._frames[dt]
+        view = arr.reshape(arr.shape[0], H, -1, W)
         if self.device:
-            F = self._frames[dt].shape[0]
-            view = self._frames[dt].reshape(F, -1, row_elems)
-            self._frames[dt] = view.at[jnp.asarray(fidx),
+            self._frames[dt] = view.at[jnp.asarray(fidx), :,
                                        jnp.asarray(sidx)].set(
-                jnp.asarray(rows).astype(view.dtype)).reshape(F, -1)
+                jnp.asarray(rows).astype(view.dtype)).reshape(arr.shape)
             return
-        F = self._frames[dt].shape[0]
-        view = self._frames[dt].reshape(F, -1, row_elems)
-        view[fidx, sidx] = \
+        view[fidx, :, sidx] = \
             np.asarray(rows.astype(dt) if hasattr(rows, "astype") else rows)
 
     def _gather_host(self, dt: str, idx: np.ndarray,
@@ -363,7 +376,9 @@ class PagePool:
         return jnp.asarray(flat[:size].reshape(shape))
 
     def frames_array(self, dtype) -> jax.Array:
-        """Expose raw physical frames (what the RNIC reads)."""
+        """Expose raw physical frames (what the RNIC reads) as
+        (F, page_elems)."""
+        f = self._frames[self._dt(dtype)]
         if self.device:
-            return self._frames[self._dt(dtype)]
-        return jnp.asarray(self._frames[self._dt(dtype)])
+            return f.reshape(f.shape[0], self.page_elems)
+        return jnp.asarray(f)

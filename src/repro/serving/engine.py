@@ -57,20 +57,19 @@ class ServingEngine:
         self.active: List[int] = []
         self.waiting: List[int] = []
         self._rid = 0
-        self._block_params = self._flatten_blocks()
 
-    def _flatten_blocks(self):
-        """Per-layer param slices (unstacked views for the python-loop path)."""
-        out = []
+    def _layer_params(self):
+        """Yield (spec, params) per layer in execution order.  Stacked
+        params are sliced as each layer runs: holding an unstacked copy
+        would double the model's device memory."""
         for g, gp in zip(self.cfg.groups, self.params["groups"]):
             for r in range(g.repeat):
                 for bi, spec in enumerate(g.unit):
                     bp = gp["blocks"][bi]
                     if getattr(spec, "shared", False):
-                        out.append((spec, bp))
+                        yield spec, bp
                     else:
-                        out.append((spec, jax.tree.map(lambda x: x[r], bp)))
-        return out
+                        yield spec, jax.tree.map(lambda x: x[r], bp)
 
     # -- request lifecycle -----------------------------------------------------
 
@@ -129,7 +128,7 @@ class ServingEngine:
         k_pt, v_pt, lens = self.kv.batch_tables(sids)
 
         h = L.embed_tokens(self.params["embed"], cfg, toks[:, None], dt)
-        for li, (spec, bp) in enumerate(self._block_params):
+        for li, (spec, bp) in enumerate(self._layer_params()):
             hn = L.rms_norm(h, bp["norm1"]["scale"], cfg.norm_eps)
             q, k1, v1 = L._project_qkv(bp["attn"], hn, spec, cfg, pos[:, None])
             # write this token's K/V into the reserved slot, then attend
@@ -172,10 +171,8 @@ class ServingEngine:
             kf.append(seq.k_pages[layer, col])
             vf.append(seq.v_pages[layer, col])
             slots.append(slot)
-        B = len(sids)
-        row = kv.K * kv.hd
-        kv.pool.write_rows(kv.dtype, kf, slots, k_rows.reshape(B, -1), row)
-        kv.pool.write_rows(kv.dtype, vf, slots, v_rows.reshape(B, -1), row)
+        kv.pool.write_rows(kv.dtype, kf, slots, k_rows)
+        kv.pool.write_rows(kv.dtype, vf, slots, v_rows)
 
     # -- scheduler ------------------------------------------------------------------
 

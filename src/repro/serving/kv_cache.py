@@ -1,8 +1,9 @@
 """Paged KV cache on top of the MITOSIS PagePool.
 
-One page = `page_tokens` KV slots of one layer (K heads x head_dim), for K or
-V.  Sequences hold per-layer page tables; `fork_sequence` shares pages
-copy-on-write with refcounts — the serving-side realization of the paper's
+One page = `page_tokens` KV slots of one layer, for K or V, stored
+head-major as (K heads, page_tokens, head_dim) — the block layout the
+paged_attention kernel reads (kernels/paged_attention).  Sequences hold
+per-layer page tables; `fork_sequence` shares pages copy-on-write with refcounts — the serving-side realization of the paper's
 zero-serialization state transfer (children fork the parent's prefix pages
 and append privately).
 """
@@ -49,7 +50,7 @@ class PagedKV:
 
     def frames_view(self):
         f = self.pool.frames_array(self.dtype)
-        return f.reshape(f.shape[0], self.Tp, self.K, self.hd)
+        return f.reshape(f.shape[0], self.K, self.Tp, self.hd)
 
     # -- sequence lifecycle ----------------------------------------------------
 
@@ -103,12 +104,9 @@ class PagedKV:
         """k_rows/v_rows: (L, K, hd) for the new token."""
         seq = self.seqs[sid]
         col, slot = self.ensure_writable_slot(sid)
-        row = self.K * self.hd
         slots = [slot] * self.L
-        self.pool.write_rows(self.dtype, seq.k_pages[:, col], slots,
-                             k_rows.reshape(self.L, -1), row)
-        self.pool.write_rows(self.dtype, seq.v_pages[:, col], slots,
-                             v_rows.reshape(self.L, -1), row)
+        self.pool.write_rows(self.dtype, seq.k_pages[:, col], slots, k_rows)
+        self.pool.write_rows(self.dtype, seq.v_pages[:, col], slots, v_rows)
         seq.length += 1
 
     def write_prefill(self, sid: int, k, v) -> None:
@@ -123,8 +121,10 @@ class PagedKV:
         if pad:
             padw = ((0, 0), (0, pad), (0, 0), (0, 0))
             k, v = jnp.pad(k, padw), jnp.pad(v, padw)
-        k = k.reshape(L, ncols, self.Tp, self.K, self.hd)
-        v = v.reshape(L, ncols, self.Tp, self.K, self.hd)
+        # token-major (L, S, K, hd) -> head-major pages (L, ncols, K, Tp, hd)
+        shape = (L, ncols, self.Tp, self.K, self.hd)
+        k = k.reshape(shape).transpose(0, 1, 3, 2, 4)
+        v = v.reshape(shape).transpose(0, 1, 3, 2, 4)
         for c in range(ncols):
             self.pool.write_pages(self.dtype, seq.k_pages[:, c],
                                   k[:, c].reshape(L, -1))

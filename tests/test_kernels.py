@@ -75,8 +75,8 @@ def test_cow_scatter_leaves_other_frames():
 def test_paged_attention_sweep(B, K, G, hd, Tp, P, F, dtype):
     keys = [jax.random.PRNGKey(i) for i in range(5)]
     q = jax.random.normal(keys[0], (B, K, G, hd), dtype)
-    pk = jax.random.normal(keys[1], (F, Tp, K, hd), dtype)
-    pv = jax.random.normal(keys[2], (F, Tp, K, hd), dtype)
+    pk = jax.random.normal(keys[1], (F, K, Tp, hd), dtype)
+    pv = jax.random.normal(keys[2], (F, K, Tp, hd), dtype)
     pt = jax.random.randint(keys[3], (B, P), 0, F)
     vt = jax.random.randint(keys[4], (B, P), 0, F)
     lengths = jax.random.randint(keys[4], (B,), 1, P * Tp + 1)
@@ -92,8 +92,8 @@ def test_paged_attention_sweep(B, K, G, hd, Tp, P, F, dtype):
 def test_paged_attention_window_starts():
     B, K, G, hd, Tp, P, F = 2, 1, 2, 128, 8, 4, 12
     q = jax.random.normal(jax.random.PRNGKey(0), (B, K, G, hd))
-    pk = jax.random.normal(jax.random.PRNGKey(1), (F, Tp, K, hd))
-    pv = jax.random.normal(jax.random.PRNGKey(2), (F, Tp, K, hd))
+    pk = jax.random.normal(jax.random.PRNGKey(1), (F, K, Tp, hd))
+    pv = jax.random.normal(jax.random.PRNGKey(2), (F, K, Tp, hd))
     pt = jax.random.randint(jax.random.PRNGKey(3), (B, P), 0, F)
     lengths = jnp.array([30, 25], jnp.int32)
     starts = jnp.array([10, 0], jnp.int32)

@@ -101,9 +101,9 @@ def test_cow_write_after_fork_isolates(setup):
     parent_page = kv.seqs[s0].k_pages[0, 0]
     child_page = kv.seqs[s1].k_pages[0, 0]
     assert parent_page != child_page
-    np.testing.assert_array_equal(np.asarray(f[parent_page, :3]),
-                                  np.ones((3, 1, 8), np.float32))
-    np.testing.assert_array_equal(np.asarray(f[child_page, 3]),
+    np.testing.assert_array_equal(np.asarray(f[parent_page, :, :3]),
+                                  np.ones((1, 3, 8), np.float32))
+    np.testing.assert_array_equal(np.asarray(f[child_page, :, 3]),
                                   np.full((1, 8), 9.0, np.float32))
 
 
