@@ -298,6 +298,7 @@ def check_kernel_meters(meters, impl: str) -> dict:
 def fork_serve(cfg, *, backend: str, impl: str, seed: int, log=print,
                **serve_kw):
     """Both one-chip phases for ``cfg``; returns the kernel-path meter."""
+    dispatch.reset_meters()     # count only what this smoke resolves
     params, net_meter = fork_phase(cfg, seed=seed, backend=backend, log=log)
     kernel_meter, ref_meter = serve_phase(cfg, params, backend=backend,
                                           seed=seed, log=log, **serve_kw)

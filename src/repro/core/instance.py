@@ -72,7 +72,7 @@ class ModelInstance:
         # stats keys are historical: "pages_rdma" counts pages served by the
         # (possibly two-sided) page transport, "pages_rpc" the fallback daemon
         self.stats = {"faults": 0, "pages_rdma": 0, "pages_rpc": 0,
-                      "pages_cached": 0, "pages_local": 0, "cow_pages": 0,
+                      "pages_cached": 0, "pages_local": 0,
                       "prefetch_issued": 0, "prefetch_used": 0,
                       "prefetch_wasted": 0,
                       "assemble_full": 0, "assemble_patch_pages": 0}
@@ -374,7 +374,6 @@ class ModelInstance:
         pages = np.atleast_1d(np.asarray(pages))
         self._adopt_pages(vma, pages, data)
         vma.mark_dirty(pages)
-        self.stats["cow_pages"] += len(pages)
 
     def add_tensor(self, name: str, arr) -> None:
         """Pre-materialize new state into the instance (workflow globals,

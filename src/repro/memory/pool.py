@@ -35,6 +35,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import tracing
 from repro.kernels import dispatch
 from repro.kernels.cow_scatter.ops import cow_scatter, cow_scatter_runs
 from repro.kernels.page_gather.kernel import LANE
@@ -113,6 +114,13 @@ class PagePool:
     def _count(self, key: str, n: int = 1) -> None:
         if self.meter is not None:
             self.meter[key] += n
+
+    @staticmethod
+    def _crossed(way: str, nbytes: int) -> None:
+        """Count one copy across the host-device boundary (``way`` is
+        "h2d" or "d2h") for the innermost open span (repro.tracing)."""
+        tracing.count(f"pool.{way}_bytes", nbytes)
+        tracing.count(f"pool.{way}_copies")
 
     def _drain_kernel_meters(self) -> None:
         # surface the dispatch layer's chosen-impl counts (recorded by the
@@ -246,8 +254,11 @@ class PagePool:
             return
         self._count("pool.scatter_pages", int(idx.size))
         if self.device:
-            payload = jnp.asarray(np.asarray(pages)) \
-                if isinstance(pages, np.ndarray) else jnp.asarray(pages)
+            if isinstance(pages, jax.Array):
+                payload = pages
+            else:
+                payload = jnp.asarray(np.asarray(pages))
+                self._crossed("h2d", payload.nbytes)
             starts, lens = frame_runs(idx)
             if starts.size * 2 <= idx.size:
                 self._frames[dt] = cow_scatter_runs(
@@ -261,8 +272,11 @@ class PagePool:
             return
         dst = self._frames[dt]
         if not (isinstance(pages, np.ndarray) and pages.dtype == dst.dtype):
+            on_device = isinstance(pages, jax.Array)
             pages = np.asarray(
                 pages.astype(dt) if hasattr(pages, "astype") else pages)
+            if on_device:
+                self._crossed("d2h", pages.nbytes)
         starts, lens = frame_runs(idx)
         if starts.size * HOST_RUN_MIN_AVG <= idx.size:
             # extent-run commit: one memcpy per contiguous run
@@ -286,12 +300,18 @@ class PagePool:
         arr = self._frames[dt]
         view = arr.reshape(arr.shape[0], H, -1, W)
         if self.device:
+            if not isinstance(rows, jax.Array):
+                rows = jnp.asarray(rows)
+                self._crossed("h2d", rows.nbytes)
             self._frames[dt] = view.at[jnp.asarray(fidx), :,
                                        jnp.asarray(sidx)].set(
-                jnp.asarray(rows).astype(view.dtype)).reshape(arr.shape)
+                rows.astype(view.dtype)).reshape(arr.shape)
             return
-        view[fidx, :, sidx] = \
-            np.asarray(rows.astype(dt) if hasattr(rows, "astype") else rows)
+        on_device = isinstance(rows, jax.Array)
+        rows = np.asarray(rows.astype(dt) if hasattr(rows, "astype") else rows)
+        if on_device:
+            self._crossed("d2h", rows.nbytes)
+        view[fidx, :, sidx] = rows
 
     def _gather_host(self, dt: str, idx: np.ndarray,
                      out: Optional[np.ndarray] = None) -> np.ndarray:
@@ -327,7 +347,9 @@ class PagePool:
                                   backend=self.kernel_backend)
             self._drain_kernel_meters()
             return out
-        return jnp.asarray(self._gather_host(dt, idx))
+        pages = self._gather_host(dt, idx)
+        self._crossed("h2d", pages.nbytes)
+        return jnp.asarray(pages)
 
     def read_pages_host(self, dtype, frames,
                         out: Optional[np.ndarray] = None) -> np.ndarray:
@@ -342,6 +364,7 @@ class PagePool:
         idx = np.asarray(frames, np.int32)
         if self.device:
             data = np.asarray(self.read_pages(dtype, frames))
+            self._crossed("d2h", data.nbytes)
             if out is not None:
                 out[...] = data
                 return out
@@ -373,6 +396,7 @@ class PagePool:
                         self._frames[dt].dtype)
         self._gather_host(dt, idx, out=flat.reshape(idx.size,
                                                     self.page_elems))
+        self._crossed("h2d", size * flat.itemsize)
         return jnp.asarray(flat[:size].reshape(shape))
 
     def frames_array(self, dtype) -> jax.Array:
@@ -381,4 +405,5 @@ class PagePool:
         f = self._frames[self._dt(dtype)]
         if self.device:
             return f.reshape(f.shape[0], self.page_elems)
+        self._crossed("h2d", f.nbytes)
         return jnp.asarray(f)

@@ -16,6 +16,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import tracing
 from repro.memory.pool import PagePool
 
 
@@ -49,8 +50,9 @@ class PagedKV:
     # -- frames view for the attention kernel ---------------------------------
 
     def frames_view(self):
-        f = self.pool.frames_array(self.dtype)
-        return f.reshape(f.shape[0], self.K, self.Tp, self.hd)
+        with tracing.span("kv.frames_view"):
+            f = self.pool.frames_array(self.dtype)
+            return f.reshape(f.shape[0], self.K, self.Tp, self.hd)
 
     # -- sequence lifecycle ----------------------------------------------------
 
@@ -111,26 +113,27 @@ class PagedKV:
 
     def write_prefill(self, sid: int, k, v) -> None:
         """k/v: (L, S, K, hd) — bulk-write a prefilled prefix."""
-        L, S = k.shape[0], k.shape[1]
-        seq = self.seqs[sid]
-        assert seq.length == 0
-        ncols = -(-S // self.Tp)
-        for _ in range(ncols):
-            self._alloc_column(seq)
-        pad = ncols * self.Tp - S
-        if pad:
-            padw = ((0, 0), (0, pad), (0, 0), (0, 0))
-            k, v = jnp.pad(k, padw), jnp.pad(v, padw)
-        # token-major (L, S, K, hd) -> head-major pages (L, ncols, K, Tp, hd)
-        shape = (L, ncols, self.Tp, self.K, self.hd)
-        k = k.reshape(shape).transpose(0, 1, 3, 2, 4)
-        v = v.reshape(shape).transpose(0, 1, 3, 2, 4)
-        for c in range(ncols):
-            self.pool.write_pages(self.dtype, seq.k_pages[:, c],
-                                  k[:, c].reshape(L, -1))
-            self.pool.write_pages(self.dtype, seq.v_pages[:, c],
-                                  v[:, c].reshape(L, -1))
-        seq.length = S
+        with tracing.span("kv.write_prefill"):
+            L, S = k.shape[0], k.shape[1]
+            seq = self.seqs[sid]
+            assert seq.length == 0
+            ncols = -(-S // self.Tp)
+            for _ in range(ncols):
+                self._alloc_column(seq)
+            pad = ncols * self.Tp - S
+            if pad:
+                padw = ((0, 0), (0, pad), (0, 0), (0, 0))
+                k, v = jnp.pad(k, padw), jnp.pad(v, padw)
+            # token-major (L, S, K, hd) -> head-major pages (L, ncols, K, Tp, hd)
+            shape = (L, ncols, self.Tp, self.K, self.hd)
+            k = k.reshape(shape).transpose(0, 1, 3, 2, 4)
+            v = v.reshape(shape).transpose(0, 1, 3, 2, 4)
+            for c in range(ncols):
+                self.pool.write_pages(self.dtype, seq.k_pages[:, c],
+                                      k[:, c].reshape(L, -1))
+                self.pool.write_pages(self.dtype, seq.v_pages[:, c],
+                                      v[:, c].reshape(L, -1))
+            seq.length = S
 
     # -- fork (the paper's state transfer) ---------------------------------------
 
