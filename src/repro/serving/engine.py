@@ -102,7 +102,7 @@ class ServingEngine:
             toks = jnp.asarray(req.prompt, jnp.int32)[None, :]
             cache_len = ((len(req.prompt) + self.kv.Tp - 1) // self.kv.Tp) * self.kv.Tp
             # no sync at its end: the forward's device time overlaps the
-            # dispatches that follow, and kv.write_prefill's first copy waits
+            # dispatches that follow, and kv.write_prefill's copy waits for it
             with tracing.span("lm.prefill"):
                 logits, caches = lm.prefill(self.params, self.cfg, toks,
                                             cache_len)

@@ -10,6 +10,7 @@ and append privately).
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Dict, List, Optional
 
 import jax
@@ -29,6 +30,21 @@ class SeqKV:
     v_pages: np.ndarray
     # copy-on-write: pages shared with an ancestor are read-only
     shared_mask: np.ndarray       # (P,) bool — True = shared (not writable)
+
+
+@functools.partial(jax.jit, static_argnames=("page_tokens", "dtype"))
+def _prefill_pages(k, v, *, page_tokens, dtype):
+    """Token-major k/v (L, S, K, hd) -> head-major pages
+    (2 * L * ncols, K * page_tokens * hd) in ``dtype``: K's pages over V's,
+    each layer-major, the order of ``concat(k_pages.ravel(),
+    v_pages.ravel())``; the last column is zero-padded."""
+    L, S, K, hd = k.shape
+    ncols = -(-S // page_tokens)
+    kv = jnp.stack([k, v])
+    kv = jnp.pad(kv, ((0, 0), (0, 0), (0, ncols * page_tokens - S),
+                      (0, 0), (0, 0)))
+    kv = kv.reshape(2, L, ncols, page_tokens, K, hd).transpose(0, 1, 2, 4, 3, 5)
+    return kv.reshape(2 * L * ncols, K * page_tokens * hd).astype(dtype)
 
 
 class PagedKV:
@@ -112,27 +128,23 @@ class PagedKV:
         seq.length += 1
 
     def write_prefill(self, sid: int, k, v) -> None:
-        """k/v: (L, S, K, hd) — bulk-write a prefilled prefix."""
+        """k/v: (L, S, K, hd) — bulk-write a prefilled prefix: one
+        allocation each for K and V, one pool commit for both."""
         with tracing.span("kv.write_prefill"):
             L, S = k.shape[0], k.shape[1]
             seq = self.seqs[sid]
             assert seq.length == 0
             ncols = -(-S // self.Tp)
-            for _ in range(ncols):
-                self._alloc_column(seq)
-            pad = ncols * self.Tp - S
-            if pad:
-                padw = ((0, 0), (0, pad), (0, 0), (0, 0))
-                k, v = jnp.pad(k, padw), jnp.pad(v, padw)
-            # token-major (L, S, K, hd) -> head-major pages (L, ncols, K, Tp, hd)
-            shape = (L, ncols, self.Tp, self.K, self.hd)
-            k = k.reshape(shape).transpose(0, 1, 3, 2, 4)
-            v = v.reshape(shape).transpose(0, 1, 3, 2, 4)
-            for c in range(ncols):
-                self.pool.write_pages(self.dtype, seq.k_pages[:, c],
-                                      k[:, c].reshape(L, -1))
-                self.pool.write_pages(self.dtype, seq.v_pages[:, c],
-                                      v[:, c].reshape(L, -1))
+            kf = self.pool.alloc(self.dtype, L * ncols)
+            vf = self.pool.alloc(self.dtype, L * ncols)
+            frames = np.concatenate([kf, vf])
+            self.refcount.update(dict.fromkeys(frames.tolist(), 1))
+            seq.k_pages = kf.reshape(L, ncols)
+            seq.v_pages = vf.reshape(L, ncols)
+            seq.shared_mask = np.zeros(ncols, bool)
+            self.pool.write_pages(
+                self.dtype, frames,
+                _prefill_pages(k, v, page_tokens=self.Tp, dtype=self.dtype))
             seq.length = S
 
     # -- fork (the paper's state transfer) ---------------------------------------

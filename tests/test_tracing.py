@@ -209,15 +209,17 @@ def test_engine_spans_one_queue_wait_and_prefill_per_request(served):
 
 
 def test_write_prefill_copies_two_pages_a_column(served, hello_cfg):
+    """A K and a V page per layer and column, all in one copy."""
     eng, prompts, recs = served
     pre = {r.id: r.rid for r in recs if r.name == "engine.prefill"}
     L, K, hd = hello_cfg.num_layers, hello_cfg.num_kv_heads, hello_cfg.head_dim
-    for r in recs:
-        if r.name == "kv.write_prefill":
-            cols = -(-prompts[pre[r.parent]] // eng.kv.Tp)
-            assert _copies(r) == {"pool.d2h_copies": 2 * cols,
-                                  "pool.d2h_bytes": 2 * cols * L * eng.kv.Tp
-                                  * K * hd * 4}
+    spans = [r for r in recs if r.name == "kv.write_prefill"]
+    assert len(spans) == len(prompts)
+    for r in spans:
+        cols = -(-prompts[pre[r.parent]] // eng.kv.Tp)
+        assert _copies(r) == {"pool.d2h_copies": 1,
+                              "pool.d2h_bytes": 2 * cols * L * eng.kv.Tp
+                              * K * hd * 4}
 
 
 def test_decode_copies_the_pool_each_layer_and_one_row_a_request(
