@@ -1,2 +1,3 @@
-"""The chip benchmark: cells, traffic, metric readers and the reference that
-decides ``correct``.  ``python chipbench/run.py --help`` runs one cell."""
+"""The chip benchmark: cells, traffic, metric readers and the block
+references that decide ``correct``.  ``python chipbench/run.py --help``
+runs one cell."""

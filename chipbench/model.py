@@ -1,77 +1,37 @@
 """A configuration file made into the program's model, and its weights.
 
-The model is the repository's registry architecture (``run.arch``) cut to
-the file's depth; every width is checked against the file before a run.
-The weights are the benchmark's own: one jitted call makes every leaf on
-the device from ``--seed``, in the program's parameter layout and its master
-dtype.  The reference makes them again the same way after the window, so it
-takes nothing the program has made.
+The model is the repository's registry architecture cut to the file's
+depth by the file's reference module (``run.reference``, see
+``references/``), which also checks every width against the file and gives
+each leaf its init scale.  The weights are the benchmark's own: one jitted
+call makes every leaf on the device from ``--seed``, in the program's
+parameter layout and its master dtype.  The reference makes them again the
+same way after the window, so it takes nothing the program has made.
 """
 from __future__ import annotations
 
 import dataclasses
-import math
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
-from repro.configs.base import GroupSpec, get_arch
+from chipbench import spec
 from repro.memory.pool import PAGE_ELEMS
 from repro.models import lm
 
 
-
-
 @dataclasses.dataclass(frozen=True)
-class Dims:
-    """The numbers the harness, work counts and reference need."""
-    layers: int
-    d: int
-    heads: int
-    kv_heads: int
-    head_dim: int
-    d_ff: int
-    vocab: int
-    gated: bool
-    tied: bool
-    norm_eps: float
-    rope_theta: float
+class Model:
+    """A configuration as the harness runs it: its reference module, the
+    module's dims, and the program's architecture."""
+    ref: object
+    dims: object
+    cfg: object
 
 
-def dims(conf: dict) -> Dims:
-    run = conf["run"]
-    w = run["widths"]
-    return Dims(layers=int(conf[run["depth_key"]]), d=int(conf[w["d_model"]]),
-                heads=int(conf[w["num_heads"]]),
-                kv_heads=int(conf[w["num_kv_heads"]]) if "num_kv_heads" in w
-                else int(run["num_kv_heads"]),
-                head_dim=int(run["head_dim"]), d_ff=int(conf[w["d_ff"]]),
-                vocab=int(conf[w["vocab_size"]]),
-                gated=run["mlp"] == "gated_silu",
-                tied=bool(run["tied_embeddings"]),
-                norm_eps=float(run["norm_eps"]),
-                rope_theta=float(run["rope_theta"]))
-
-
-def arch(conf: dict):
-    """The registry architecture at the file's depth, its widths checked."""
-    run, dm = conf["run"], dims(conf)
-    base = get_arch(run["arch"])
-    if len(base.groups) != 1 or len(base.groups[0].unit) != 1:
-        raise ValueError(f"{run['arch']}: expected one repeated block")
-    cfg = dataclasses.replace(
-        base, name=conf["name"],
-        groups=(GroupSpec(unit=base.groups[0].unit, repeat=dm.layers),),
-        param_dtype=run["param_dtype"], compute_dtype=run["compute_dtype"])
-    want = {"d_model": dm.d, "num_heads": dm.heads, "num_kv_heads": dm.kv_heads,
-            "head_dim": dm.head_dim, "d_ff": dm.d_ff, "vocab_size": dm.vocab,
-            "mlp_gated": dm.gated, "tie_embeddings": dm.tied,
-            "norm_eps": dm.norm_eps, "rope_theta": dm.rope_theta}
-    got = {k: getattr(cfg, k) for k in want}
-    if got != want:
-        raise ValueError(f"{conf['name']}: registry {got} != file {want}")
-    return cfg
+def load(conf: dict) -> Model:
+    ref = spec.reference_module(conf)
+    return Model(ref, ref.dims(conf), ref.arch(conf))
 
 
 def seed_key(seed: int):
@@ -81,32 +41,22 @@ def seed_key(seed: int):
                               (seed >> 32) & 0xFFFFFFFF)
 
 
-def _std(path: str, dm: Dims) -> float:
-    """Init scale of one leaf, by its name in the program's layout."""
-    leaf = path.rsplit("/", 1)[-1]
-    table = {"wq": dm.d, "wk": dm.d, "wv": dm.d, "wo": dm.heads * dm.head_dim,
-             "wi": dm.d, "wg": dm.d, "wd": dm.d_ff, "out": dm.d}
-    if leaf == "tok":
-        return 0.02
-    if leaf == "scale":
-        return 0.1
-    if leaf not in table:
-        raise KeyError(f"no init scale for leaf {path}")
-    return 1.0 / math.sqrt(table[leaf])
-
-
 def leaf_paths(tree) -> list:
     return ["/".join(str(getattr(k, "key", getattr(k, "idx", k))) for k in p)
             for p, _ in jax.tree_util.tree_flatten_with_path(tree)[0]]
 
 
-def make_weights_fn(cfg, dm: Dims):
+def _shapes(cfg):
+    return jax.eval_shape(lambda k: lm.init_params(k, cfg),
+                          jax.random.PRNGKey(0))
+
+
+def make_weights_fn(m: Model):
     """jit(seed key) -> the full parameter pytree, on the device."""
-    shapes = jax.eval_shape(lambda k: lm.init_params(k, cfg),
-                            jax.random.PRNGKey(0))
+    shapes = _shapes(m.cfg)
     treedef = jax.tree.structure(shapes)
     specs = jax.tree.leaves(shapes)
-    stds = [_std(p, dm) for p in leaf_paths(shapes)]
+    stds = [m.ref.init_std(p, m.dims) for p in leaf_paths(shapes)]
 
     @jax.jit
     def make(key):
@@ -120,9 +70,8 @@ def make_weights_fn(cfg, dm: Dims):
 
 def param_pages(cfg) -> int:
     """Pool pages the model's parameters take, leaf by leaf."""
-    shapes = jax.eval_shape(lambda k: lm.init_params(k, cfg),
-                            jax.random.PRNGKey(0))
-    return sum(max(1, -(-x.size // PAGE_ELEMS)) for x in jax.tree.leaves(shapes))
+    return sum(max(1, -(-x.size // PAGE_ELEMS))
+               for x in jax.tree.leaves(_shapes(cfg)))
 
 
 def page_bytes(cfg) -> int:

@@ -25,7 +25,7 @@ import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 from jax.sharding import SingleDeviceSharding  # noqa: E402
 
-from chipbench import model, reference, spec  # noqa: E402
+from chipbench import model, spec  # noqa: E402
 from repro.kernels.cow_scatter.kernel import cow_scatter_runs  # noqa: E402
 from repro.kernels.page_gather.kernel import page_gather  # noqa: E402
 from repro.kernels.paged_attention.kernel import paged_attention  # noqa: E402
@@ -44,14 +44,15 @@ def _report(what: str, fn, *args, **kw) -> None:
 def rehearse(bench: dict, wl: dict, dev) -> None:
     conf = spec.load_config(bench, wl["config"])
     mix = spec.load_traffic(wl["traffic"])
-    cfg, dm = model.arch(conf), model.dims(conf)
+    m = model.load(conf)
+    cfg, dm = m.cfg, m.dims
     one = SingleDeviceSharding(dev)
     sds = lambda s, d: jax.ShapeDtypeStruct(s, d, sharding=one)  # noqa: E731
     npages = model.param_pages(cfg)
     print(f"{wl['name']}: {npages} pages of {model.PAGE_ELEMS} "
           f"{cfg.param_dtype}", flush=True)
     key = sds((2,), jnp.uint32)
-    _report("weights", model.make_weights_fn(cfg, dm), key)
+    _report("weights", model.make_weights_fn(m), key)
     shapes = jax.eval_shape(lambda k: lm.init_params(k, cfg),
                             jax.random.PRNGKey(0))
     big = max(-(-x.size // model.PAGE_ELEMS) for x in jax.tree.leaves(shapes))
@@ -70,19 +71,19 @@ def rehearse(bench: dict, wl: dict, dev) -> None:
     batch = int(mix.get("max_active", 1))
     pages = -(-(longest + 1) // tp)
     ct = jnp.dtype(cfg.compute_dtype)
-    G = dm.heads // dm.kv_heads
-    kv = sds((batch * 2 * dm.layers * pages, dm.kv_heads, tp, dm.head_dim), ct)
+    K, hd = cfg.num_kv_heads, cfg.head_dim
+    kv = sds((batch * 2 * cfg.num_layers * pages, K, tp, hd), ct)
     _report(f"paged_attention B={batch} P={pages}",
             lambda q, k, v, t, n: paged_attention(q, k, v, t, n,
                                                   interpret=False),
-            sds((batch, dm.kv_heads, G, dm.head_dim), ct), kv, kv,
+            sds((batch, K, cfg.num_heads // K, hd), ct), kv, kv,
             sds((batch, pages), jnp.int32), sds((batch,), jnp.int32))
     params = jax.tree.map(lambda x: sds(x.shape, x.dtype), shapes)
     S = max(vals)
     _report(f"prefill S={S}", lambda p, t: lm.prefill(
         p, cfg, t, -(-S // tp) * tp), params, sds((1, S), jnp.int32))
     T = longest - 1
-    _report(f"reference T={T}", lambda p, t: reference.logits_at(
+    _report(f"reference T={T}", lambda p, t: m.ref.logits_at(
         p, t, dm=dm, first=max(vals) - 1), params, sds((T,), jnp.int32))
 
 

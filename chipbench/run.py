@@ -62,7 +62,7 @@ def latencies(recs: list) -> dict:
 
 def end_to_end(recs: list, setup_s: float, peak_bytes: int) -> dict:
     lat = latencies(recs)
-    return {"invoke_ms_p95": percentile(lat["last"], 95),
+    return {"invoke_ms_p50": percentile(lat["last"], 50),
             "ttft_ms_p95": percentile(lat["first"], 95),
             "itl_ms_p95": percentile(lat["gaps"], 95),
             "hbm_peak_gib": peak_bytes / 2**30,
@@ -92,9 +92,11 @@ def run_cell(bench: dict, wl: dict, seed: int, seconds: float, trace: bool,
     kind = device_kind or dev.device_kind
     sp = Spans(annotate=trace)
     drv = spec.driver_module(mix["kind"]).Driver(conf, mix, seed, sp, log)
+    before_spans = time.perf_counter() - t_start
     drv.setup()
-    sched = generate.schedule(mix, seed, seconds, drv.dm.vocab)
-    setup_split = {k: sum(sp.durations(k)) for k in sp.spans}
+    sched = generate.schedule(mix, seed, seconds, drv.model.dims.vocab)
+    setup_split = {"before_spans": before_spans,
+                   **{k: sum(sp.durations(k)) for k in sp.spans}}
     sp.reset()
     compiles = Counter()
     jax.monitoring.register_event_listener(
@@ -134,7 +136,7 @@ def run_cell(bench: dict, wl: dict, seed: int, seconds: float, trace: bool,
         red = trace_reduce.reduce(next(tdir.rglob("*.xplane.pb")))
         shutil.rmtree(tdir, ignore_errors=True)
         rec = types.SimpleNamespace(spans=sp, work=drv.work(), trace=red,
-                                    peaks=work.peaks(kind), dims=drv.dm,
+                                    peaks=work.peaks(kind), dims=drv.model.dims,
                                     window_s=window_s)
         for m in spec.cell_metrics(bench, wl["name"], trace=True):
             v = spec.metric_reader(m["name"]).read(rec)
@@ -154,13 +156,13 @@ def run_cell(bench: dict, wl: dict, seed: int, seconds: float, trace: bool,
     sums = getattr(drv, "sums", None)
     if sums is not None:
         sums = {k: jax.device_get(v) for k, v in sums.items()}
-    cfg, dm = drv.cfg, drv.dm
+    model = drv.model
     drv.release()
     del drv
     gc.collect()
     t_ref = time.perf_counter()
     picked = check.sample(recs, int(mix["sample_requests"]), seed)
-    read = check.readings(cfg, dm, seed, picked, sums=sums, control=control)
+    read = check.readings(model, seed, picked, sums=sums, control=control)
     read["failed"] = failed
     correct, checks = check.verdict(read, {**limits, "failed": 0}, failed)
     if control:
@@ -203,7 +205,10 @@ def main(argv=None) -> int:
     import jax
     enable_compile_cache()
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    t_backend = time.perf_counter()
     devs = jax.devices()
+    log(f"start-up: imports {t_backend - T_START:.3f} s, backend "
+        f"{time.perf_counter() - t_backend:.3f} s")
     if devs[0].platform != "tpu" or len(devs) < int(wl["chips"]):
         log(f"needs {wl['chips']} TPU chip(s); JAX found {len(devs)} "
             f"{devs[0].platform} device(s)")

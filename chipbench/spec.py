@@ -5,6 +5,9 @@ mixes and per-layer metrics; each lives in a file of its own here, found by
 its name alone, so a later cell adds files and never edits the harness:
 
 * ``configs/<file>``            a configuration (named by its ``file`` entry)
+* ``references/<name>.py``      the block module a configuration names in
+                                ``run.reference``: its widths, init scales,
+                                float32 forward and work counts
 * ``traffic/<mix>.json``        a traffic mix; its ``kind`` names the driver
                                 module ``traffic/<kind>.py``
 * ``metrics/<metric>.py``       the reader of one per-layer metric
@@ -50,6 +53,13 @@ def load_traffic(name: str) -> dict:
 
 def load_limits(workload_name: str) -> dict:
     return json.loads((HERE / "limits" / f"{workload_name}.json").read_text())
+
+
+def reference_module(conf: dict):
+    """The module ``references/<name>.py`` that ``conf["run"]["reference"]``
+    names."""
+    return importlib.import_module(
+        f"chipbench.references.{conf['run']['reference']}")
 
 
 def driver_module(kind: str):

@@ -50,7 +50,7 @@ def main(argv=None) -> int:
     drv = spec.driver_module(mix["kind"]).Driver(conf, mix, args.seed, sp,
                                                   run.log)
     drv.setup()
-    vocab = drv.dm.vocab
+    vocab = drv.model.dims.vocab
 
     def window(rate, seconds, seed, burst=False):
         sched = generate.schedule(mix, seed, seconds, vocab, rate=rate)

@@ -22,10 +22,9 @@ from repro.memory.pool import PagePool
 class System:
     def __init__(self, conf: dict, seed: int, spans, log):
         self.conf, self.seed, self.sp, self.log = conf, seed, spans, log
-        self.dm = model.dims(conf)
-        self.cfg = model.arch(conf)
-        self.npages = model.param_pages(self.cfg)
-        self.page_bytes = model.page_bytes(self.cfg)
+        self.model = model.load(conf)
+        self.npages = model.param_pages(self.model.cfg)
+        self.page_bytes = model.page_bytes(self.model.cfg)
 
     def seed_parent(self, on_device=None) -> None:
         """Weights from the seed, on the device in one call, then into the
@@ -35,7 +34,7 @@ class System:
         (the serving shapes' warm-up), so what it holds stays below what a
         child and its serving hold in the window."""
         sp = self.sp
-        make = model.make_weights_fn(self.cfg, self.dm)
+        make = model.make_weights_fn(self.model)
         with sp.span("setup.weights"):
             w = jax.block_until_ready(make(model.seed_key(self.seed)))
         if on_device is not None:
@@ -47,7 +46,8 @@ class System:
         self.net = Network()
         self.parent = NodeRuntime("parent", self.net, pool_frames=self.npages)
         with sp.span("setup.seed_parent"):
-            inst = ModelInstance.create(self.parent, self.cfg.name, host)
+            inst = ModelInstance.create(self.parent, self.model.cfg.name,
+                                        host)
             del host
             self.handle = self.parent.prepare_fork(inst)
         with sp.span("setup.child_node"):
@@ -67,16 +67,15 @@ class System:
     def engine(self, params, kv_frames: int = 0,
                page_tokens: int = 16) -> ServingEngine:
         """A serving engine over ``params``; ``kv_frames`` > 0 gives its KV
-        pool that many frames up front (a server's fixed KV capacity)."""
-        eng = ServingEngine(self.cfg, params, page_tokens=page_tokens,
+        pool that many frames up front (a server's fixed KV capacity), in
+        the layout the engine chose."""
+        eng = ServingEngine(self.model.cfg, params, page_tokens=page_tokens,
                             backend="auto")
         if kv_frames:
-            dm = self.dm
-            eng.kv = PagedKV(dm.layers, dm.kv_heads, dm.head_dim,
-                             page_tokens=page_tokens,
-                             dtype=jnp.dtype(self.cfg.compute_dtype),
-                             pool=PagePool(page_tokens * dm.kv_heads
-                                           * dm.head_dim,
+            kv = eng.kv
+            eng.kv = PagedKV(kv.L, kv.K, kv.hd, page_tokens=page_tokens,
+                             dtype=kv.dtype,
+                             pool=PagePool(kv.page_elems,
                                            initial_frames=kv_frames))
         return eng
 

@@ -60,6 +60,18 @@ def test_workload_resolves(wl):
     assert spec.cell_metrics(BENCH, wl["name"], trace=True)
 
 
+@pytest.mark.parametrize("c", BENCH["configs"], ids=lambda c: c["name"])
+def test_config_reference_resolves(c):
+    """Each configuration's ``run.reference`` names a block module that the
+    harness finds by that name and that builds the configuration."""
+    conf = spec.load_config(BENCH, c["name"])
+    ref = spec.reference_module(conf)
+    for fn in ("dims", "arch", "init_std", "logits_at", "prefill_flops",
+               "decode_flops", "paged_attention_work"):
+        assert callable(getattr(ref, fn)), fn
+    assert ref.dims(conf).vocab > 0 and ref.arch(conf).name == conf["name"]
+
+
 @pytest.mark.parametrize("m", BENCH["per_layer"], ids=lambda m: m["name"])
 def test_per_layer_metric_resolves(m):
     assert callable(spec.metric_reader(m["name"]).read)

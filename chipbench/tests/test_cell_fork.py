@@ -5,6 +5,7 @@ import jax.numpy as jnp
 
 from chipbench.tests.cells import run_tiny
 from repro.core.instance import ModelInstance
+from repro.memory.pool import PagePool
 from repro.serving import engine
 
 
@@ -12,7 +13,7 @@ def test_sound_run_is_correct():
     r = run_tiny("fork")
     assert r["correct"] and r["failed"] == 0 and r["attempted"] == 4
     assert r["checks"]["child_params_differ"]["value"] == 0
-    assert set(r["metrics"]) == {"invoke_ms_p95", "hbm_peak_gib", "setup_s"}
+    assert set(r["metrics"]) == {"invoke_ms_p50", "hbm_peak_gib", "setup_s"}
     assert list(r)[-1] == "checks"
 
 
@@ -38,3 +39,10 @@ def test_altered_token_is_caught(monkeypatch):
     assert not r["correct"]
     c = r["checks"]["served_logit_gap"]
     assert c["value"] > c["limit"]
+
+
+def test_kv_state_left_unchanged_is_caught(monkeypatch):
+    """Decode writes no new K/V: every later token attends stale pages."""
+    monkeypatch.setattr(PagePool, "write_rows", lambda *a, **k: None)
+    r = run_tiny("fork", output_tokens=8)
+    assert not r["correct"]

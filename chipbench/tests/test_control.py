@@ -14,3 +14,14 @@ def test_fp8_control_fails_where_the_program_passes():
     assert r["checks"]["served_logit_gap"]["value"] > limit
     assert r["checks"]["served_logit_gap"]["limit"] == limit
 
+
+
+def test_fp8_control_fails_the_fork_cell_where_the_program_passes():
+    """The same on the fork cell's path, with the eight tokens an
+    invocation serves."""
+    r = run_tiny("fork", control=True, output_tokens=8)
+    limit = tiny.LIMITS["served_logit_gap"]
+    assert r["readings"]["program_logit_gap"] <= limit
+    assert r["readings"]["child_params_differ"] == 0
+    assert not r["correct"]
+    assert r["checks"]["served_logit_gap"]["value"] > limit

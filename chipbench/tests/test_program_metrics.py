@@ -144,7 +144,7 @@ def test_traced_tiny_serving_run_reports_the_program_metrics():
     are read."""
     with warnings.catch_warnings():
         warnings.simplefilter("ignore", RuntimeWarning)
-        r = run.run_cell(tiny.bench("serve"), tiny.workload("serve"),
+        r = run.run_cell(spec.load_benchmark(), tiny.workload("serve"),
                          2**31 + 4099, 1.0, True, conf=tiny.TINY_CONF,
                          mix=tiny.SERVE_MIX, limits=tiny.LIMITS,
                          device_kind="TPU v5 lite",

@@ -2,13 +2,12 @@
 traffic kinds and limits, in the same shape as the cells' files."""
 from __future__ import annotations
 
-from chipbench import spec
-
 TINY_CONF = {
     "name": "micro-hello-test",
     "hidden_size": 64, "intermediate_size": 256, "num_attention_heads": 4,
     "num_key_value_heads": 4, "num_hidden_layers": 2, "vocab_size": 512,
-    "run": {"arch": "micro-hello", "depth_key": "num_hidden_layers",
+    "run": {"arch": "micro-hello", "reference": "dense",
+            "depth_key": "num_hidden_layers",
             "widths": {"d_model": "hidden_size", "d_ff": "intermediate_size",
                        "num_heads": "num_attention_heads",
                        "num_kv_heads": "num_key_value_heads",
@@ -44,16 +43,3 @@ def workload(kind: str) -> dict:
     """The real cell of this kind, run on the tiny configuration and mix."""
     return {"name": CELLS[kind], "config": TINY_CONF["name"], "traffic": kind,
             "chips": 1}
-
-
-# The fork cell is not in BENCHMARK.json yet (its knee and sets are still to
-# be measured on the chip); its tiny run reports the metric it would.
-FORK_LATENCY = {"name": "invoke_ms_p95", "unit": "ms", "better": "lower",
-                "source": "host_clock", "workloads": [CELLS["fork"]]}
-
-
-def bench(kind: str) -> dict:
-    b = spec.load_benchmark()
-    if kind == "fork":
-        b["end_to_end"].append(FORK_LATENCY)
-    return b

@@ -16,14 +16,14 @@ from chipbench import work
 from chipbench.system import System, leaf_sums, step_and_stamp
 from chipbench.traffic.generate import warm_prompts
 
-LATENCY = "invoke_ms_p95"
+LATENCY = "invoke_ms_p50"
 
 
 class Driver:
     def __init__(self, conf: dict, mix: dict, seed: int, spans, log):
         self.sys = System(conf, seed, spans, log)
         self.mix, self.seed, self.sp, self.log = mix, seed, spans, log
-        self.dm, self.cfg = self.sys.dm, self.sys.cfg
+        self.model = self.sys.model
         self.sums = {}          # request idx -> per-leaf bit sums (device)
 
     def setup(self) -> None:
@@ -40,7 +40,8 @@ class Driver:
     def _warm_serve(self, weights) -> None:
         """One request of each prompt length the mix draws, each on an
         engine of its own, as the window serves them."""
-        for prompt in warm_prompts(self.mix, self.seed, self.dm.vocab).values():
+        for prompt in warm_prompts(self.mix, self.seed,
+                                   self.model.dims.vocab).values():
             eng = self.sys.engine(weights)
             eng.submit(prompt.tolist(), max_tokens=int(self.mix["output_tokens"]))
             while eng.waiting or eng.active:
@@ -95,11 +96,12 @@ class Driver:
 
     def work(self) -> dict:
         """Required work of what the window ran, for the metric readers."""
-        c, dm, pb = self.sp.counters, self.dm, self.sys.page_bytes
+        c, pb = self.sp.counters, self.sys.page_bytes
+        ref, dm = self.model.ref, self.model.dims
         lens = self.sp.samples["prompt_lengths"]
         new = int(self.mix["output_tokens"]) - 1
-        flops = sum(work.prefill_flops(dm, n)
-                    + sum(work.decode_flops(dm, [n + i]) for i in range(new))
+        flops = sum(ref.prefill_flops(dm, n)
+                    + sum(ref.decode_flops(dm, [n + i]) for i in range(new))
                     for n in lens)
         return {"page_gather": (0, work.page_move_bytes(
                     c["pool.assemble_pages"], pb)),

@@ -13,7 +13,6 @@ from __future__ import annotations
 import collections
 import time
 
-from chipbench import work
 from chipbench.system import System, step_and_stamp
 from chipbench.traffic.generate import warm_prompts
 
@@ -24,11 +23,11 @@ class Driver:
     def __init__(self, conf: dict, mix: dict, seed: int, spans, log):
         self.sys = System(conf, seed, spans, log)
         self.mix, self.seed, self.sp, self.log = mix, seed, spans, log
-        self.dm, self.cfg = self.sys.dm, self.sys.cfg
+        self.model = self.sys.model
         self.tp = int(mix["kv_page_tokens"])
         self.max_active = int(mix["max_active"])
         longest = max(mix["prompt_tokens"]["values"]) + int(mix["output_tokens"])
-        self.kv_frames = (self.max_active * 2 * self.dm.layers
+        self.kv_frames = (self.max_active * 2 * self.model.cfg.num_layers
                           * -(-(longest + 1) // self.tp))
 
     def setup(self) -> None:
@@ -69,7 +68,8 @@ class Driver:
                                      page_tokens=self.tp), self.max_active
         if n_max >= self.tp:
             raise ValueError("max_active must stay below kv_page_tokens")
-        for n, prompt in warm_prompts(self.mix, self.seed, self.dm.vocab).items():
+        prompts = warm_prompts(self.mix, self.seed, self.model.dims.vocab)
+        for n, prompt in prompts.items():
             with self.sp.span("warm"):
                 r0 = eng.submit(prompt.tolist(), max_tokens=self.tp - 1)
                 eng.step()
@@ -128,15 +128,16 @@ class Driver:
         return recs
 
     def work(self) -> dict:
-        dm, s = self.dm, self.sp.samples
-        att = [work.paged_attention_work(dm, lens, self.tp)
+        ref, dm, s = self.model.ref, self.model.dims, self.sp.samples
+        att = [ref.paged_attention_work(dm, lens, self.tp)
                for lens in s["decode_lengths"]]
-        return {"prefill_flops": sum(work.prefill_flops(dm, n)
+        layers = self.model.cfg.num_layers
+        return {"prefill_flops": sum(ref.prefill_flops(dm, n)
                                      for n in s["prefill_lengths"]),
-                "decode_flops": sum(work.decode_flops(dm, lens)
+                "decode_flops": sum(ref.decode_flops(dm, lens)
                                     for lens in s["decode_lengths"]),
-                "paged_attention": (dm.layers * sum(f for f, _ in att),
-                                    dm.layers * sum(b for _, b in att))}
+                "paged_attention": (layers * sum(f for f, _ in att),
+                                    layers * sum(b for _, b in att))}
 
     def release(self) -> None:
         self.eng = self.params = None
